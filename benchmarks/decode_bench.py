@@ -1,5 +1,10 @@
 """Decode hot-path benchmark: TP (unrolled/scanned/fused) vs PP vs TP×PP.
 
+A CPU tool: its child process runs on ``JAX_PLATFORMS=cpu`` with four
+forced host devices, so its times are CPU wall times, never device
+metrics.  It stays that until a benchmark measured on the chip replaces
+it; ``chip_smoke.py`` is what runs on the chip today.
+
 Times seven decode strategies on a 4-device host-platform mesh (reduced
 configs, CPU-sized):
 
@@ -269,6 +274,7 @@ def _measure(dry_run: bool = False):
 def _run_subprocess(dry_run: bool = False):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + REPO
     cmd = [sys.executable, "-m", "benchmarks.decode_bench", "--measure"]
     if dry_run:
@@ -287,7 +293,7 @@ def _run_subprocess(dry_run: bool = False):
 def rows(dry_run: bool = False):
     recs, err = _run_subprocess(dry_run)
     if recs is None:
-        return [("decode/bench", 0.0, f"subprocess_failed;stderr={err}")]
+        raise RuntimeError(f"decode_bench child failed: {err}")
     path = DRY_PATH if dry_run else OUT_PATH
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
@@ -310,11 +316,8 @@ def main(dry_run: bool = False):
     mode = "dry-run smoke" if dry_run else f"fused×{N_TOKENS}"
     print(f"Decode paths — TP unrolled/scanned/fused vs PP vs TP×PP "
           f"({mode}, 4-device host mesh, B={BATCH})")
-    rs = rows(dry_run)
-    for r in rs:
+    for r in rows(dry_run):
         print(f"  {r[0]:46s} {r[2]}")
-    if dry_run and any(r[0] == "decode/bench" for r in rs):
-        raise SystemExit("decode_bench smoke failed")
     if not dry_run and os.path.exists(OUT_PATH):
         print(f"  wrote {OUT_PATH}")
 

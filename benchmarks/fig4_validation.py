@@ -7,6 +7,8 @@ compiled HLO collective counts/bytes are compared against Eq. 1.  This is the
 paper's validation plot as an equality check.
 
 Runs in a subprocess so the 4-device host-platform flag stays contained.
+A CPU tool (``JAX_PLATFORMS=cpu`` in the child): it compiles, it never
+times a device.
 """
 import json
 import os
@@ -60,6 +62,7 @@ def _measure():
 def rows():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + REPO
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.fig4_validation", "--measure"],
@@ -76,8 +79,8 @@ def rows():
                             f"ar_bytes={rec['measured_ar_bytes']};"
                             f"match={'EXACT' if match else 'MISMATCH'}"))
     if not out:
-        out.append(("fig4/validation", 0.0,
-                    f"subprocess_failed;stderr={r.stderr[-200:]}"))
+        raise RuntimeError(
+            f"fig4_validation child failed: {r.stderr[-300:]}")
     return out
 
 

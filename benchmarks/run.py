@@ -1,9 +1,17 @@
 """Benchmark aggregator: one module per paper table/figure + assigned-scope
-benches.  Prints ``name,us_per_call,derived`` CSV."""
+benches.  Prints ``name,us_per_call,derived`` CSV.
+
+Each module runs in its own child process (``--module NAME``), one after
+another, and this parent never imports JAX: a process that has touched
+JAX holds the accelerator, and a module that starts children of its own
+(decode_bench, fig4_validation) would then find it taken.  Exits 1 when
+any module fails.
+"""
 from __future__ import annotations
 
+import os
+import subprocess
 import sys
-import traceback
 
 MODULES = [
     "benchmarks.table3_tp",
@@ -20,24 +28,32 @@ MODULES = [
     "benchmarks.perf_variants",
     "benchmarks.decode_bench",
 ]
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+
+
+def run_module(modname: str) -> None:
+    """Print one module's rows as CSV lines (runs in the child)."""
+    import importlib
+    for name, us, derived in importlib.import_module(modname).rows():
+        print(f"{name},{us:.2f},{derived}", flush=True)
 
 
 def main() -> None:
-    import importlib
-    print("name,us_per_call,derived")
+    print("name,us_per_call,derived", flush=True)
     failures = []
     for modname in MODULES:
-        try:
-            mod = importlib.import_module(modname)
-            for name, us, derived in mod.rows():
-                print(f"{name},{us:.2f},{derived}")
-        except Exception:
+        r = subprocess.run(
+            [sys.executable, "-m", "benchmarks.run", "--module", modname],
+            cwd=REPO)
+        if r.returncode:
             failures.append(modname)
-            traceback.print_exc(file=sys.stderr)
     if failures:
         print(f"# FAILED modules: {failures}", file=sys.stderr)
         sys.exit(1)
 
 
 if __name__ == "__main__":
-    main()
+    if "--module" in sys.argv:
+        run_module(sys.argv[sys.argv.index("--module") + 1])
+    else:
+        main()

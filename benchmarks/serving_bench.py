@@ -1,5 +1,11 @@
 """Serving benchmark: continuous batching under Poisson traffic (paper §V-C).
 
+A CPU tool: its child process runs on ``JAX_PLATFORMS=cpu`` with four
+forced host devices and ``reduced()`` configs, so every time it reports is
+a CPU wall time, never a device metric.  It stays that until a benchmark
+measured on the chip replaces it; ``chip_smoke.py`` is what runs on the
+chip today.
+
 Drives the continuous-batching scheduler (runtime/scheduler.py) over each
 DecodeBackend with mixed-length request traces at increasing arrival rates,
 producing the throughput-vs-latency curves the paper's SLO section draws from
@@ -654,6 +660,7 @@ def _measure(dry_run: bool = False):
 def _run_subprocess(dry_run: bool = False):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + REPO
     cmd = [sys.executable, "-m", "benchmarks.serving_bench", "--measure"]
     if dry_run:
@@ -672,7 +679,7 @@ def _run_subprocess(dry_run: bool = False):
 def rows(dry_run: bool = False):
     recs, err = _run_subprocess(dry_run)
     if recs is None:
-        return [("serve/bench", 0.0, f"subprocess_failed;stderr={err}")]
+        raise RuntimeError(f"serving_bench child failed: {err}")
     path = DRY_PATH if dry_run else OUT_PATH
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
@@ -699,11 +706,8 @@ def main(dry_run: bool = False):
     print(f"Continuous-batching serving — gspmd/tp2/pp2 + paged, short, "
           f"long-context & overload-admission traces ({mode}, "
           f"Poisson arrivals)")
-    rs = rows(dry_run)
-    for r in rs:
+    for r in rows(dry_run):
         print(f"  {r[0]:60s} {r[2]}")
-    if dry_run and any(r[0] == "serve/bench" for r in rs):
-        raise SystemExit("serving_bench smoke failed")
     out = DRY_PATH if dry_run else OUT_PATH
     if os.path.exists(out):
         print(f"  wrote {out}")

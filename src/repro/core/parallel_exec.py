@@ -46,8 +46,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.config.base import ModelConfig
 from repro.core.commodel import DEFAULT_QUANT_CHUNK, stage_layer_partition
@@ -92,6 +91,22 @@ def tp_param_specs(cfg: ModelConfig, tp_axis: str = "tp",
         "lm_head": P(None, tp_axis),
         "final_norm": P(None),
     }
+
+
+def place_params(params, specs, mesh: Mesh):
+    """``device_put`` a parameter pytree onto ``mesh`` under ``specs`` —
+    done once per engine, so no jitted step reshards the model per call."""
+    return jax.device_put(params, jax.tree.map(
+        lambda sp: NamedSharding(mesh, sp), specs,
+        is_leaf=lambda x: isinstance(x, P)))
+
+
+def tp_place_params(cfg: ModelConfig, params, mesh: Mesh):
+    """Place a ``Model.init`` pytree on a single-stage engine mesh with
+    ``tp_param_specs`` (vocab/column/row shards on "tp", replicated over
+    "cp")."""
+    _, axis = _tp_axis_of(mesh)
+    return place_params(params, tp_param_specs(cfg, tp_axis=axis), mesh)
 
 
 # ---------------------------------------------------------------------------
@@ -170,12 +185,15 @@ def quantized_psum(x, axis, t: int, quant: str = "int8",
             raise ValueError(f"int4 packs two values per byte and ships "
                              f"h/t-element blocks: h={h} must divide 2t="
                              f"{2 * t}")
+        # half-split packing (byte i = elements i and i + h/2): packed block
+        # j carries hidden slices j of BOTH halves, so rank j reduces those
         pa = jax.lax.all_to_all(nibble_pack(q), axis,
                                 split_axis=x.ndim - 1,
                                 concat_axis=x.ndim - 1, tiled=True)
-        qa = nibble_unpack(pa)          # t source copies of the local block
-        r = qa.astype(jnp.int32).reshape(*x.shape[:-1], t, h // t) \
-              .sum(axis=-2)             # exact: |r| <= 7t
+        qa = nibble_unpack(pa)          # [low halves | high halves] of the
+        #                                 t source copies of the local block
+        r = qa.astype(jnp.int32).reshape(*x.shape[:-1], 2, t, h // (2 * t)) \
+              .sum(axis=-2).reshape(*x.shape[:-1], h // t)  # exact: |r| <= 7t
         rq = jnp.clip(jnp.round(r.astype(jnp.float32) / t),
                       -7, 7).astype(jnp.int8)
         pg = jax.lax.all_gather(nibble_pack(rq), axis, axis=x.ndim - 1,
@@ -355,8 +373,15 @@ def _cp_last_hidden(x, last, axis_cp: str):
 # ---------------------------------------------------------------------------
 
 
+def _auto_axes(n: int) -> tuple:
+    """Axis types of every engine mesh: all Auto, so the jit-level
+    sharding rules are the same for the TP meshes (``jax.make_mesh``, whose
+    default is Explicit) and the PP stage meshes (``Mesh(...)``)."""
+    return (AxisType.Auto,) * n
+
+
 def make_tp_mesh(t: int) -> Mesh:
-    return jax.make_mesh((t,), ("tp",))
+    return jax.make_mesh((t,), ("tp",), axis_types=_auto_axes(1))
 
 
 def make_tp_cp_mesh(t: int, c: int = 1) -> Mesh:
@@ -368,7 +393,8 @@ def make_tp_cp_mesh(t: int, c: int = 1) -> Mesh:
     if not shape:
         shape = [(1, "tp")]
     return jax.make_mesh(tuple(s for s, _ in shape),
-                         tuple(n for _, n in shape))
+                         tuple(n for _, n in shape),
+                         axis_types=_auto_axes(len(shape)))
 
 
 def _tp_axis_of(mesh: Mesh):
@@ -467,10 +493,10 @@ def tp_prefill(cfg: ModelConfig, mesh: Mesh, cache_w: int = None,
         return logits, cache
 
     out_cache_spec = None if cache_w is None else _cache_spec(axis)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, P(None, None)),
         out_specs=(P(None, None), out_cache_spec),
-        check_rep=False))
+        check_vma=False))
 
 
 def cp_prefill(cfg: ModelConfig, mesh: Mesh, cache_w: int = None,
@@ -535,10 +561,10 @@ def cp_prefill(cfg: ModelConfig, mesh: Mesh, cache_w: int = None,
         return logits, cache
 
     out_cache_spec = None if cache_w is None else _cache_spec(axis)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, P(None, "cp"), P()),
         out_specs=(P(None, None), out_cache_spec),
-        check_rep=False))
+        check_vma=False))
 
 
 def tp_decode_step(cfg: ModelConfig, mesh: Mesh, unroll: bool = True,
@@ -576,12 +602,12 @@ def tp_decode_step(cfg: ModelConfig, mesh: Mesh, unroll: bool = True,
                                heads_t, kv_t, unroll, axis, t, quant,
                                quant_chunk)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(specs, cache_spec, P(None),
                   P(None) if vector_pos else P()),
         out_specs=(P(None, None), cache_spec),
-        check_rep=False),
+        check_vma=False),
         donate_argnums=(1,) if donate else ())
 
 
@@ -615,12 +641,12 @@ def tp_generate(cfg: ModelConfig, mesh: Mesh, num_tokens: int,
                                               t, quant, quant_chunk),
             token, cache, pos, num_tokens)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(specs, cache_spec, P(None),
                   P(None) if vector_pos else P()),
         out_specs=(P(None, None), cache_spec),
-        check_rep=False),
+        check_vma=False),
         donate_argnums=(1,))
 
 
@@ -663,12 +689,12 @@ def tp_paged_step(cfg: ModelConfig, mesh: Mesh, unroll: bool = False,
         logits = _head(cfg, params, x[:, -1, :], axis)
         return logits, cache
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         fn, mesh=mesh,
         in_specs=(specs, cache_spec, P(None, None), P(None),
                   P(None, None)),
         out_specs=(P(None, None), cache_spec),
-        check_rep=False),
+        check_vma=False),
         donate_argnums=(1,) if donate else ())
 
 
@@ -765,7 +791,7 @@ class PipelineEngine:
         # same fn replicated over cp — all-local, zero collectives)
         self._mapped = t > 1 or c > 1
         self._tp_axis = "tp" if t > 1 else None
-        self._param_specs = tp_param_specs(cfg, tp_axis=self._tp_axis)
+        self._param_specs = [self._stage_param_specs(s) for s in range(p)]
         self._stage_cache_spec = _cache_spec(self._tp_axis)
         self.transfers: list = []
         self._stage_fns = [self._build_stage(s) for s in range(p)]
@@ -779,7 +805,8 @@ class PipelineEngine:
         if not axes:
             axes = [(1, "tp")]
         return Mesh(np.asarray(devs).reshape([s for s, _ in axes]),
-                    tuple(n for _, n in axes))
+                    tuple(n for _, n in axes),
+                    axis_types=_auto_axes(len(axes)))
 
     # -- shared stage fragments (traced inside each stage's jit) -----------
     def _boundary_in(self, x_or_tokens):
@@ -805,10 +832,15 @@ class PipelineEngine:
     def _head_out(self, params, x_last):
         return _head(self.cfg, params, x_last, self._tp_axis)
 
-    def _stage_blocks(self, params, lo, hi):
-        return jax.tree.map(
-            lambda a: jax.lax.slice_in_dim(a, lo, hi, axis=0),
-            params["blocks"])
+    def _stage_keys(self, s: int) -> tuple:
+        """Parameter groups stage s reads: its layer slice, plus the
+        embedding on the first stage and the head on the last."""
+        return (("blocks",) + (("embed",) if s == 0 else ())
+                + (("final_norm", "lm_head") if s == self.p - 1 else ()))
+
+    def _stage_param_specs(self, s: int) -> dict:
+        full = tp_param_specs(self.cfg, tp_axis=self._tp_axis)
+        return {k: full[k] for k in self._stage_keys(s)}
 
     def _boundary_pair_spec(self, seq_shard: bool = False):
         """Sharding of the two-tensor [B, S|1, h/t] boundary pair;
@@ -860,7 +892,7 @@ class PipelineEngine:
                     cache_w)
             if self.unroll:
                 caches = []
-                for l in range(lo, hi):
+                for l in range(hi - lo):
                     x, cl = layer(_layer_slice(params["blocks"], l), x)
                     caches.append(cl)
                 cache = (jax.tree.map(lambda *xs: jnp.stack(xs), *caches)
@@ -869,8 +901,7 @@ class PipelineEngine:
                 def body(h, pl):
                     return layer(pl, h)
 
-                x, cache = jax.lax.scan(body, x,
-                                        self._stage_blocks(params, lo, hi))
+                x, cache = jax.lax.scan(body, x, params["blocks"])
             if last_stage:
                 x_last = (_cp_last_hidden(x, last, "cp") if c > 1
                           else x[:, -1, :])
@@ -890,10 +921,10 @@ class PipelineEngine:
                     else (out_spec, self._stage_cache_spec))
         extra_in = (P(),) if c > 1 else ()
         if self._mapped:
-            mapped = shard_map(stage_fn, mesh=mesh,
-                               in_specs=(self._param_specs, in_x_spec)
-                               + extra_in,
-                               out_specs=full_out, check_rep=False)
+            mapped = jax.shard_map(stage_fn, mesh=mesh,
+                                   in_specs=(self._param_specs[s], in_x_spec)
+                                   + extra_in,
+                                   out_specs=full_out, check_vma=False)
         else:
             mapped = stage_fn               # single-device stage
         return jax.jit(mapped), mesh
@@ -917,9 +948,9 @@ class PipelineEngine:
                  if first else self._boundary_in(x_or_tokens))
             if self.unroll:
                 new_cache = []
-                for i, l in enumerate(range(lo, hi)):
+                for i in range(hi - lo):
                     x, c = _tp_layer_step(
-                        cfg, _layer_slice(params["blocks"], l), x, pos,
+                        cfg, _layer_slice(params["blocks"], i), x, pos,
                         _layer_slice(cache, i), axis, heads_t, kv_t,
                         t, self.quant, self.quant_chunk)
                     new_cache.append(c)
@@ -933,7 +964,7 @@ class PipelineEngine:
                     return h, c
 
                 x, cache = jax.lax.scan(
-                    body, x, (self._stage_blocks(params, lo, hi), cache))
+                    body, x, (params["blocks"], cache))
             out = (self._head_out(params, x[:, 0, :]) if last
                    else self._boundary_out(x))
             return out, cache
@@ -942,12 +973,12 @@ class PipelineEngine:
         in_x_spec = P(None) if first else self._boundary_pair_spec()
         pos_spec = P(None) if vector_pos else P()
         if self._mapped:
-            mapped = shard_map(
+            mapped = jax.shard_map(
                 fn, mesh=mesh,
-                in_specs=(self._param_specs, self._stage_cache_spec,
+                in_specs=(self._param_specs[s], self._stage_cache_spec,
                           in_x_spec, pos_spec),
                 out_specs=(out_spec, self._stage_cache_spec),
-                check_rep=False)
+                check_vma=False)
         else:
             mapped = fn
         # fast path donates the cache (in-place update); paper-parity mode
@@ -975,9 +1006,9 @@ class PipelineEngine:
                  else self._boundary_in(x_or_tokens))
             if self.unroll:
                 new_cache = []
-                for i, l in enumerate(range(lo, hi)):
+                for i in range(hi - lo):
                     x, c = _tp_layer_paged(
-                        cfg, _layer_slice(params["blocks"], l), x, pos,
+                        cfg, _layer_slice(params["blocks"], i), x, pos,
                         _layer_slice(cache, i), bt, axis, heads_t, kv_t)
                     new_cache.append(c)
                 cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_cache)
@@ -989,7 +1020,7 @@ class PipelineEngine:
                     return h, c
 
                 x, cache = jax.lax.scan(
-                    body, x, (self._stage_blocks(params, lo, hi), cache))
+                    body, x, (params["blocks"], cache))
             out = (self._head_out(params, x[:, -1, :]) if last
                    else self._boundary_out(x))
             return out, cache
@@ -998,12 +1029,12 @@ class PipelineEngine:
         in_x_spec = (P(None, None) if first
                      else self._boundary_pair_spec())
         if self._mapped:
-            mapped = shard_map(
+            mapped = jax.shard_map(
                 fn, mesh=self.meshes[s],
-                in_specs=(self._param_specs, self._stage_cache_spec,
+                in_specs=(self._param_specs[s], self._stage_cache_spec,
                           in_x_spec, P(None), P(None, None)),
                 out_specs=(out_spec, self._stage_cache_spec),
-                check_rep=False)
+                check_vma=False)
         else:
             mapped = fn
         donate = () if self.unroll else (1,)
@@ -1029,15 +1060,18 @@ class PipelineEngine:
         return self._decode_stage_fns[vector_pos]
 
     # -- driver --------------------------------------------------------------
-    def _shard_params(self, params, mesh):
-        return jax.device_put(
-            params, jax.tree.map(
-                lambda sp: NamedSharding(mesh, sp), self._param_specs,
-                is_leaf=lambda x: isinstance(x, P)))
-
     def prepare(self, params):
-        """Place one param copy per stage (each stage reads its own layers)."""
-        return [self._shard_params(params, m) for m in self.meshes]
+        """Place each stage's share of a ``Model.init`` pytree on its own
+        mesh: its layer slice, plus the embedding (first stage) and the
+        head (last stage) — no stage holds the whole model."""
+        staged = []
+        for s, mesh in enumerate(self.meshes):
+            lo, hi = stage_layer_range(self.cfg, self.p, s)
+            part = {k: params[k] for k in self._stage_keys(s)}
+            part["blocks"] = jax.tree.map(lambda a: a[lo:hi],
+                                          params["blocks"])
+            staged.append(place_params(part, self._param_specs[s], mesh))
+        return staged
 
     def _move_boundary(self, out, s: int, phase: str, log: bool = True,
                        seq_shard: bool = False):

@@ -103,10 +103,12 @@ def chunk_quantize_pallas(x, scales, chunk: int = 128, qdtype=jnp.int8,
 
 
 def _pack_kernel(q_ref, o_ref):
-    q = q_ref[...].astype(jnp.uint8)
-    br, hp = q.shape
-    pairs = q.reshape(br, hp // 2, 2)
-    o_ref[...] = (pairs[..., 0] & 0xF) | ((pairs[..., 1] & 0xF) << 4)
+    # half-split pairing (element i with i + h/2): two contiguous slices,
+    # no lane-splitting reshape, which Mosaic cannot lower
+    m = o_ref.shape[-1]
+    lo = q_ref[:, :m].astype(jnp.int32) & 0xF
+    hi = q_ref[:, m:].astype(jnp.int32) & 0xF
+    o_ref[...] = (lo | (hi << 4)).astype(jnp.uint8)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -129,12 +131,10 @@ def nibble_pack_pallas(q, block_rows: int = 256, interpret: bool = False):
 
 
 def _unpack_kernel(b_ref, o_ref):
-    b = b_ref[...]
-    br, m = b.shape
-    lo = (b & 0xF).astype(jnp.int8)
-    hi = ((b >> 4) & 0xF).astype(jnp.int8)
-    pairs = jnp.stack([(lo ^ 8) - 8, (hi ^ 8) - 8], axis=-1)
-    o_ref[...] = pairs.reshape(br, 2 * m).astype(jnp.int8)
+    m = b_ref.shape[-1]
+    b = b_ref[...].astype(jnp.int32)
+    o_ref[:, :m] = (((b & 0xF) ^ 8) - 8).astype(jnp.int8)
+    o_ref[:, m:] = ((((b >> 4) & 0xF) ^ 8) - 8).astype(jnp.int8)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))

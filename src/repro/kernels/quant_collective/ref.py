@@ -62,21 +62,21 @@ def chunk_dequantize_ref(q, scales, chunk: int, out_dtype):
 
 def nibble_pack_ref(q):
     """Pack int4 values (int8 storage, |q| <= 7) two-per-byte: [..., h] ->
-    [..., h//2] uint8.  Even positions land in the low nibble, odd in the
-    high — two's-complement truncation to 4 bits, inverted exactly by
+    [..., h//2] uint8.  Byte i holds element i in its low nibble and
+    element i + h/2 in its high nibble (half-split pairing) —
+    two's-complement truncation to 4 bits, inverted exactly by
     ``nibble_unpack_ref``."""
     h = q.shape[-1]
     if h % 2:
         raise ValueError(f"nibble packing needs an even last axis, got {h}")
     u = q.astype(jnp.uint8)
-    pairs = u.reshape(*q.shape[:-1], h // 2, 2)
-    return (pairs[..., 0] & 0xF) | ((pairs[..., 1] & 0xF) << 4)
+    return (u[..., :h // 2] & 0xF) | ((u[..., h // 2:] & 0xF) << 4)
 
 
 def nibble_unpack_ref(b):
     """Unpack two-per-byte nibbles back to int8: [..., m] -> [..., 2m],
-    sign-extending each 4-bit field ((n ^ 8) - 8)."""
+    low nibbles first, then high nibbles, sign-extending each 4-bit field
+    ((n ^ 8) - 8)."""
     lo = (b & 0xF).astype(jnp.int8)
     hi = ((b >> 4) & 0xF).astype(jnp.int8)
-    pairs = jnp.stack([(lo ^ 8) - 8, (hi ^ 8) - 8], axis=-1)
-    return pairs.reshape(*b.shape[:-1], b.shape[-1] * 2).astype(jnp.int8)
+    return jnp.concatenate([(lo ^ 8) - 8, (hi ^ 8) - 8], axis=-1)

@@ -7,6 +7,7 @@ initialization, and only launch/dryrun.py sets the 512-device host platform.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,8 +16,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     data parallelism across the DCN/ICI boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """All-Auto axis types, like the engine meshes in core/parallel_exec.py
+    (``jax.make_mesh`` would default to Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

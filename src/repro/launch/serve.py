@@ -24,6 +24,8 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.configs import get_config
     from repro.models.transformer import get_model
     from repro.runtime.engine import InferenceEngine

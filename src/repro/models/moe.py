@@ -113,7 +113,6 @@ def moe_ffn_local(cfg: ModelConfig, p, x, mesh):
     dependent gather/scatter forces full-activation all-gathers across the
     mesh (the dominant collective term in the mixtral/deepseek baselines).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, h = x.shape
@@ -157,8 +156,9 @@ def moe_ffn_local(cfg: ModelConfig, p, x, mesh):
             aux = jax.lax.pmean(aux, bdim)
         return y.reshape(Bl, Sl, h), aux
 
-    y, aux = shard_map(fn, mesh=mesh, in_specs=(pspecs, x_spec),
-                       out_specs=(x_spec, P()), check_rep=False)(p_local, x)
+    y, aux = jax.shard_map(fn, mesh=mesh, in_specs=(pspecs, x_spec),
+                           out_specs=(x_spec, P()),
+                           check_vma=False)(p_local, x)
     return y, aux
 
 

@@ -422,6 +422,13 @@ class _BackendBase:
         """One chunked-prefill pass for ``tokens`` at positions
         start..start+S-1; returns the greedy token of the chunk's last
         position (the request's first token when this is the final chunk)."""
+        return int(np.argmax(self.prefill_chunk_logits(slot, tokens, start)))
+
+    def prefill_chunk_logits(self, slot: int, tokens,
+                             start: int) -> np.ndarray:
+        """``prefill_chunk``'s pass, returning the chunk's last-position
+        logits [v] instead of their argmax — what a comparison against a
+        reference forward reads."""
         self._require_paged()
         if self.c > 1:
             raise RuntimeError(
@@ -431,8 +438,7 @@ class _BackendBase:
         chunk = np.asarray(tokens, np.int32)[None, :]
         pos = np.asarray([start], np.int32)
         bt = self.block_tables[slot:slot + 1]
-        logits = self._paged_call(chunk, pos, bt, phase="prefill")
-        return int(np.argmax(logits[0]))
+        return self._paged_call(chunk, pos, bt, phase="prefill")[0]
 
     def prefill_whole(self, slot: int, tokens, start: int = 0) -> int:
         """Monolithic prefill of one request into its allocated pages:
@@ -692,18 +698,18 @@ class TPBackend(_BackendBase):
                          owner_base=owner_base)
         if cfg.family != "dense":
             raise ValueError("explicit TP engine covers the dense family")
-        self.params = params
         self._unroll = unroll
         self.mesh = px.make_tp_cp_mesh(t, c)
-        shard = lambda sp: NamedSharding(self.mesh, sp)
-        kv_spec = shard(P(None, None, None, "tp" if t > 1 else None, None))
+        self.params = px.tp_place_params(cfg, params, self.mesh)
+        kv_spec = NamedSharding(
+            self.mesh, P(None, None, None, "tp" if t > 1 else None, None))
         if self.paged:
             self._paged_fn = px.tp_paged_step(cfg, self.mesh, unroll=unroll)
             self.cache = {
-                key: jax.device_put(
-                    jnp.zeros((cfg.num_layers, self.pool.num_pages,
-                               self.page_size, cfg.num_kv_heads,
-                               cfg.head_dim), jnp.dtype(cfg.dtype)), kv_spec)
+                key: jnp.zeros((cfg.num_layers, self.pool.num_pages,
+                                self.page_size, cfg.num_kv_heads,
+                                cfg.head_dim), jnp.dtype(cfg.dtype),
+                               device=kv_spec)
                 for key in ("k", "v")}
             if c > 1:
                 self._cp_fns = {}       # padded prompt len -> cp_prefill fn
@@ -722,10 +728,9 @@ class TPBackend(_BackendBase):
                 cfg, self.mesh, unroll=unroll, vector_pos=True,
                 quant_collectives=self.quant, quant_chunk=self.quant_chunk)
             self.cache = {
-                key: jax.device_put(
-                    jnp.zeros((cfg.num_layers, num_slots, self.cache_w,
-                               cfg.num_kv_heads, cfg.head_dim),
-                              jnp.dtype(cfg.dtype)), kv_spec)
+                key: jnp.zeros((cfg.num_layers, num_slots, self.cache_w,
+                                cfg.num_kv_heads, cfg.head_dim),
+                               jnp.dtype(cfg.dtype), device=kv_spec)
                 for key in ("k", "v")}
             self._write = jax.jit(_write_slot, donate_argnums=(0,))
 
@@ -851,42 +856,32 @@ class PPBackend(_BackendBase):
                                         quant_collectives=self.quant,
                                         quant_chunk=self.quant_chunk)
         self.staged = self.engine.prepare(params)
-        kv_spec = lambda s: NamedSharding(
-            self.engine.meshes[s],
-            P(None, None, None, "tp" if t > 1 else None, None))
+
+        def stage_cache(s, rows, width):
+            """Stage s's [L_s, rows, width, kv, D] K/V leaves, created on
+            the stage's own mesh (kv heads on "tp" when t > 1)."""
+            lo, hi = px.stage_layer_range(cfg, p, s)
+            spec = NamedSharding(
+                self.engine.meshes[s],
+                P(None, None, None, "tp" if t > 1 else None, None))
+            return {key: jnp.zeros((hi - lo, rows, width, cfg.num_kv_heads,
+                                    cfg.head_dim), jnp.dtype(cfg.dtype),
+                                   device=spec)
+                    for key in ("k", "v")}
+
         self.caches = []       # paged: per-stage page pools
         self.gcaches = None    # contiguous: per-group per-stage slot caches
         if self.paged:
-            for s in range(p):
-                lo, hi = px.stage_layer_range(cfg, p, s)
-                # per-stage page pools share ONE block-table space: logical
-                # page j of a slot lives at physical page table[j] in every
-                # stage's [L_s, P, ps, kv, D] pool
-                leaves = {
-                    key: jnp.zeros((hi - lo, self.pool.num_pages,
-                                    self.page_size, cfg.num_kv_heads,
-                                    cfg.head_dim), jnp.dtype(cfg.dtype))
-                    for key in ("k", "v")}
-                if t > 1 or c > 1:
-                    leaves = {key: jax.device_put(a, kv_spec(s))
-                              for key, a in leaves.items()}
-                self.caches.append(leaves)
+            # per-stage page pools share ONE block-table space: logical page
+            # j of a slot lives at physical page table[j] in every stage's
+            # [L_s, P, ps, kv, D] pool
+            self.caches = [stage_cache(s, self.pool.num_pages,
+                                       self.page_size) for s in range(p)]
         else:
             self.cache_w = get_model(cfg).cache_width(max_len)
 
-            def stage_cache(s):
-                lo, hi = px.stage_layer_range(cfg, p, s)
-                leaves = {
-                    key: jnp.zeros((hi - lo, self.group_size, self.cache_w,
-                                    cfg.num_kv_heads, cfg.head_dim),
-                                   jnp.dtype(cfg.dtype))
-                    for key in ("k", "v")}
-                if t > 1 or c > 1:
-                    leaves = {key: jax.device_put(a, kv_spec(s))
-                              for key, a in leaves.items()}
-                return leaves
-
-            self.gcaches = [[stage_cache(s) for s in range(p)]
+            self.gcaches = [[stage_cache(s, self.group_size, self.cache_w)
+                             for s in range(p)]
                             for _ in range(self.inflight)]
         self._writes = [jax.jit(_write_slot, donate_argnums=(0,))
                         for _ in range(p)]

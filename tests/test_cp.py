@@ -20,7 +20,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
@@ -100,9 +99,9 @@ def test_ring_kv_assemble_is_bitwise_and_ordered():
 
     # replicated out spec: each worker's assembled copy must equal the
     # unsharded input bitwise — blocks landed at their absolute offsets
-    fn_full = shard_map(lambda b: layers.ring_kv_assemble(b, "cp", c),
-                        mesh=mesh, in_specs=P(None, "cp"),
-                        out_specs=P(None, None), check_rep=False)
+    fn_full = jax.shard_map(lambda b: layers.ring_kv_assemble(b, "cp", c),
+                            mesh=mesh, in_specs=P(None, "cp"),
+                            out_specs=P(None, None), check_vma=False)
     np.testing.assert_array_equal(np.asarray(jax.jit(fn_full)(k)),
                                   np.asarray(k))
 
@@ -114,8 +113,8 @@ def test_ring_kv_assemble_is_bitwise_and_ordered():
         s_loc = b.shape[1]
         return jax.lax.dynamic_slice_in_dim(full, idx * s_loc, s_loc, axis=1)
 
-    fn_own = shard_map(own_block, mesh=mesh, in_specs=P(None, "cp"),
-                       out_specs=P(None, "cp"), check_rep=False)
+    fn_own = jax.shard_map(own_block, mesh=mesh, in_specs=P(None, "cp"),
+                           out_specs=P(None, "cp"), check_vma=False)
     np.testing.assert_array_equal(np.asarray(jax.jit(fn_own)(k)),
                                   np.asarray(k))
 
@@ -148,10 +147,10 @@ def test_block_level_cp_branch_matches_plain_attention(setup):
         return y, cache
 
     specs = jax.tree.map(lambda _: P(), pl)
-    mapped = jax.jit(shard_map(
+    mapped = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(specs, P(None, "cp"), P(None, None)),
         out_specs=(P(None, "cp"), {"k": P(), "v": P()}),
-        check_rep=False))
+        check_vma=False))
     got, got_cache = mapped(pl, x, positions)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5)
     # the ring assembly itself is bitwise; the projection matmul on the

@@ -406,3 +406,26 @@ def test_no_decode_step_while_queue_waits(setup):
     Scheduler(backend, clock=clock).run([r])
     assert backend.decode_calls == 1
     assert clock.now() >= 50.0
+
+
+@needs_mesh
+def test_explicit_backends_place_params_and_pools_once(setup):
+    """TPBackend places its parameters with ``tp_param_specs`` at
+    construction; every PP stage's page pool starts on that stage's mesh,
+    t = 1 included (not on the first device)."""
+    from jax.sharding import NamedSharding
+    from repro.core import parallel_exec as px
+    cfg, params = setup
+    tp = make_backend("tp", cfg, params, num_slots=2, max_len=MAX_LEN, t=2,
+                      paged=True, page_size=PAGE)
+    specs = px.tp_param_specs(cfg)
+    placed = jax.tree.map(lambda a, sp: a.sharding == NamedSharding(
+        tp.mesh, sp), tp.params, specs,
+        is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
+    assert all(jax.tree.leaves(placed))
+    pp = make_backend("pp", cfg, params, num_slots=2, max_len=MAX_LEN, t=1,
+                      p=2, paged=True, page_size=PAGE)
+    for s, pool in enumerate(pp.caches):
+        for leaf in pool.values():
+            assert leaf.sharding.device_set == set(pp.engine.meshes[s]
+                                                   .devices.flat)

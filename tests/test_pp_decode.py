@@ -254,3 +254,24 @@ def test_hybrid_comm_ops_uneven_split_counts():
     assert got_ar == pytest.approx(comp["allreduce"], rel=1e-12)
     assert cm.total_volume(odd) == pytest.approx(
         cm.v_hybrid(cfg5, 128, 128, 2, 2), rel=1e-12)
+
+
+@needs_mesh
+@pytest.mark.parametrize("t,p", [(1, 4), (2, 2)])
+def test_prepare_places_only_each_stages_share(t, p):
+    """Each stage holds its own layer slice on its own devices, the
+    embedding only on the first stage and the head only on the last — no
+    stage holds the whole model."""
+    cfg, params, _ = _setup(num_layers=4)
+    eng = px.PipelineEngine(cfg, t=t, p=p, unroll=False)
+    staged = eng.prepare(params)
+    for s, part in enumerate(staged):
+        lo, hi = px.stage_layer_range(cfg, p, s)
+        assert set(part) == ({"blocks"} | ({"embed"} if s == 0 else set())
+                             | ({"final_norm", "lm_head"}
+                                if s == p - 1 else set()))
+        np.testing.assert_array_equal(np.asarray(part["blocks"]["wq"]),
+                                      np.asarray(params["blocks"]["wq"][lo:hi]))
+        devs = set(eng.meshes[s].devices.flat)
+        for leaf in jax.tree.leaves(part):
+            assert leaf.sharding.device_set == devs

@@ -22,7 +22,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
@@ -110,7 +109,8 @@ def test_nibble_pack_unpack_roundtrip_every_value():
     """Every int4 value pair survives pack -> unpack bitwise, in every
     lane position, and the packed form is half the bytes."""
     vals = np.arange(-8, 8, dtype=np.int8)           # full 4-bit range
-    q = jnp.asarray(np.stack(np.meshgrid(vals, vals), -1).reshape(16, 32))
+    # byte i pairs elements i and i + 16: row r pairs every value with vals[r]
+    q = jnp.asarray(np.concatenate(np.meshgrid(vals, vals), -1))
     packed = nibble_pack(q)
     assert packed.dtype == jnp.uint8
     assert packed.shape == (16, 16)
@@ -180,9 +180,9 @@ def test_quant_tolerance_contract_shape():
 def _run_quantized_psum(x_ranks, t, quant, chunk):
     """shard_map quantized_psum over the first axis of [t, rows, h]."""
     mesh = px.make_tp_mesh(t)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         lambda xs: px.quantized_psum(xs, "tp", t, quant=quant, chunk=chunk),
-        mesh=mesh, in_specs=P("tp"), out_specs=P("tp"), check_rep=False))
+        mesh=mesh, in_specs=P("tp"), out_specs=P("tp"), check_vma=False))
     out = np.asarray(fn(x_ranks))
     # every rank must hold the identical dequantized sum
     for r in range(1, t):
