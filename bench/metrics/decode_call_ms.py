@@ -1,0 +1,8 @@
+"""Mean host time of a decode round's backend call in the window: page
+extension, dispatch, the device step and the logits' read-back, ms."""
+import numpy as np
+
+
+def read(run):
+    v = [t1 - t0 for t0, t1, _, _ in run.decodes if run.in_window(t0)]
+    return float(np.mean(v)) * 1e3 if v else None
