@@ -76,10 +76,12 @@ def attention_apply(cfg: ModelConfig, p, xn, positions, mask,
         # then the logical view is gathered back for attention.  Serves both
         # chunked prefill (S > 1) and paged decode (S == 1); ``pos`` is the
         # [B] vector of start positions.
-        ck, cv = layers.paged_cache_update(cache["k"], cache["v"], k, v,
-                                           pos, block_table)
-        kg = layers.paged_gather(ck, block_table)
-        vg = layers.paged_gather(cv, block_table)
+        with jax.named_scope("page_write"):
+            ck, cv = layers.paged_cache_update(cache["k"], cache["v"], k, v,
+                                               pos, block_table)
+        with jax.named_scope("page_gather"):
+            kg = layers.paged_gather(ck, block_table)
+            vg = layers.paged_gather(cv, block_table)
         pmask = layers.paged_attn_mask(kg.shape[1], pos, S)
         out = gqa_attention(q, kg, vg, pmask)
         cache_out = {"k": ck, "v": cv}
@@ -117,10 +119,13 @@ def init_dense_blocks(rng, cfg: ModelConfig, L: int, dtype):
 def dense_block_apply(cfg: ModelConfig, p, x, positions, mask,
                       cache=None, pos=None, build_cache_w=None,
                       block_table=None, cp_axis=None, cp_size: int = 1):
-    attn_out, cache_out = attention_apply(
-        cfg, p, rms_norm(x, p["ln1"], cfg.norm_eps), positions, mask,
-        cache=cache, pos=pos, build_cache_w=build_cache_w,
-        block_table=block_table, cp_axis=cp_axis, cp_size=cp_size)
-    x = x + attn_out @ p["wo"]
-    x = x + mlp_apply(p, rms_norm(x, p["ln2"], cfg.norm_eps), cfg.activation)
+    with jax.named_scope("attention"):
+        attn_out, cache_out = attention_apply(
+            cfg, p, rms_norm(x, p["ln1"], cfg.norm_eps), positions, mask,
+            cache=cache, pos=pos, build_cache_w=build_cache_w,
+            block_table=block_table, cp_axis=cp_axis, cp_size=cp_size)
+        x = x + attn_out @ p["wo"]
+    with jax.named_scope("mlp"):
+        x = x + mlp_apply(p, rms_norm(x, p["ln2"], cfg.norm_eps),
+                          cfg.activation)
     return x, cache_out, jnp.zeros((), jnp.float32)

@@ -295,14 +295,23 @@ class Model:
         attention, so the math is identical to the contiguous decode/prefill
         at the same positions (DESIGN.md §8).  Returns (last-position logits
         [B, v], new cache).
+
+        Its parts carry stable ``jax.named_scope`` names in the ops'
+        metadata, for a profiler's framework-op view: ``embed``, ``layers``
+        (the scan; inside each layer ``attention`` with its ``page_write``
+        and ``page_gather``, and ``mlp``), ``head``.
         """
-        x = self._embed(params, tokens)
+        with jax.named_scope("embed"):
+            x = self._embed(params, tokens)
         B, S = x.shape[:2]
         pos = jnp.asarray(pos, jnp.int32)
         positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-        x, aux, new_cache = self._scan_paged(params, x, positions, cache,
-                                             pos, block_table)
-        return self._head(params, x[:, -1:, :])[:, 0], new_cache
+        with jax.named_scope("layers"):
+            x, aux, new_cache = self._scan_paged(params, x, positions, cache,
+                                                 pos, block_table)
+        with jax.named_scope("head"):
+            logits = self._head(params, x[:, -1:, :])[:, 0]
+        return logits, new_cache
 
     def decode_step(self, params, cache, tokens, pos):
         """One autoregressive step.  tokens [B] int32; ``pos`` is a scalar

@@ -78,6 +78,7 @@ from repro.models.transformer import get_model
 from repro.runtime.kvpool import KVPool
 from repro.runtime.prefix_index import PrefixIndex
 from repro.runtime.schedule import DynamicPPQueue, FusedQueue
+from repro.runtime.tracing import span
 
 
 @runtime_checkable
@@ -422,7 +423,9 @@ class _BackendBase:
         """One chunked-prefill pass for ``tokens`` at positions
         start..start+S-1; returns the greedy token of the chunk's last
         position (the request's first token when this is the final chunk)."""
-        return int(np.argmax(self.prefill_chunk_logits(slot, tokens, start)))
+        logits = self.prefill_chunk_logits(slot, tokens, start)
+        with span("backend.argmax"):
+            return int(np.argmax(logits))
 
     def prefill_chunk_logits(self, slot: int, tokens,
                              start: int) -> np.ndarray:
@@ -438,7 +441,7 @@ class _BackendBase:
         chunk = np.asarray(tokens, np.int32)[None, :]
         pos = np.asarray([start], np.int32)
         bt = self.block_tables[slot:slot + 1]
-        return self._paged_call(chunk, pos, bt, phase="prefill")[0]
+        return self._paged_logits(chunk, pos, bt, phase="prefill")[0]
 
     def prefill_whole(self, slot: int, tokens, start: int = 0) -> int:
         """Monolithic prefill of one request into its allocated pages:
@@ -480,23 +483,44 @@ class _BackendBase:
         full slot batch.  Ineligible slots' block-table rows are pointed at
         the scratch page so their garbage lanes stay harmless."""
         pos = np.asarray(pos)
-        for slot in sorted(self._decodable):
-            self._claim_guard(
-                lambda s=slot: self.pool.extend(self._owner(s),
-                                                int(pos[s]) + 1))
-            self._set_table(slot)
-        self._apply_cow()
-        bt = self.block_tables.copy()
-        for slot in range(self.num_slots):
-            if slot not in self._decodable:
-                bt[slot] = 0                # scratch page (kvpool.py)
-        logits = self._paged_call(
+        with span("backend.pages") as sp:
+            claimed = self.pool.pages_claimed
+            for slot in sorted(self._decodable):
+                self._claim_guard(
+                    lambda s=slot: self.pool.extend(self._owner(s),
+                                                    int(pos[s]) + 1))
+                self._set_table(slot)
+            self._apply_cow()
+            bt = self.block_tables.copy()
+            for slot in range(self.num_slots):
+                if slot not in self._decodable:
+                    bt[slot] = 0                # scratch page (kvpool.py)
+            sp.counts["pages"] = self.pool.pages_claimed - claimed
+        logits = self._paged_logits(
             np.asarray(tokens, np.int32)[:, None],
             np.asarray(pos, np.int32), bt, phase="decode")
-        return np.asarray(np.argmax(logits, -1), np.int32)
+        with span("backend.argmax"):
+            return np.asarray(np.argmax(logits, -1), np.int32)
 
-    def _paged_call(self, tokens, pos, bt, phase: str) -> np.ndarray:
-        """(logits [B, v]) of one paged pass; updates the cache in place."""
+    def _paged_logits(self, tokens, pos, bt, phase: str) -> np.ndarray:
+        """Host logits [B, v] of one paged pass, each part under its span:
+        dispatch (inputs to the device, the jitted call's enqueue), wait
+        (the device step), fetch (the logits' copy to the host).  The copy
+        is queued before the wait, so the runtime starts it as soon as the
+        step is done, not after the wait has returned to Python."""
+        with span("backend.dispatch"):
+            logits = self._paged_call(tokens, pos, bt, phase)
+        with span("backend.wait"):
+            logits.copy_to_host_async()
+            jax.block_until_ready(logits)
+        with span("backend.fetch") as sp:
+            host = np.asarray(logits)
+            sp.counts["bytes"] = host.nbytes
+        return host
+
+    def _paged_call(self, tokens, pos, bt, phase: str):
+        """Device logits [B, v] of one paged pass; updates the cache in
+        place."""
         raise NotImplementedError
 
     def chunk_comm_ops(self, chunk_len: int, batch: int = 1) -> List[CommOp]:
@@ -649,11 +673,11 @@ class ModelBackend(_BackendBase):
         logits, small, _ = self._prefill(self.params, self._as_prompt(prompt))
         return logits, small
 
-    def _paged_call(self, tokens, pos, bt, phase: str) -> np.ndarray:
+    def _paged_call(self, tokens, pos, bt, phase: str):
         logits, self.cache = self._paged_fn(
             self.params, self.cache, jnp.asarray(tokens, jnp.int32),
             jnp.asarray(pos, jnp.int32), jnp.asarray(bt, jnp.int32))
-        return np.asarray(logits)
+        return logits
 
     def _copy_page(self, src: int, dst: int) -> None:
         self.cache = _copy_page_rows(self.cache, jnp.int32(src),
@@ -755,11 +779,11 @@ class TPBackend(_BackendBase):
         bt = jnp.asarray(self.block_tables[slot:slot + 1, :n])
         self.cache = self._seed(self.cache, small, bt)
 
-    def _paged_call(self, tokens, pos, bt, phase: str) -> np.ndarray:
+    def _paged_call(self, tokens, pos, bt, phase: str):
         logits, self.cache = self._paged_fn(
             self.params, self.cache, jnp.asarray(tokens, jnp.int32),
             jnp.asarray(pos, jnp.int32), jnp.asarray(bt, jnp.int32))
-        return np.asarray(logits)
+        return logits
 
     def _copy_page(self, src: int, dst: int) -> None:
         self.cache = _copy_page_rows(self.cache, jnp.int32(src),
@@ -910,10 +934,10 @@ class PPBackend(_BackendBase):
         self.caches = [self._seed(self.caches[s], small[s], bt)
                        for s in range(self.p)]
 
-    def _paged_call(self, tokens, pos, bt, phase: str) -> np.ndarray:
+    def _paged_call(self, tokens, pos, bt, phase: str):
         logits, self.caches = self.engine.paged_pass(
             self.staged, self.caches, tokens, pos, bt, phase=phase)
-        return np.asarray(logits)
+        return logits
 
     def _copy_page(self, src: int, dst: int) -> None:
         s, d = jnp.int32(src), jnp.int32(dst)
@@ -972,17 +996,20 @@ class PPBackend(_BackendBase):
         pos_np = np.asarray(np.asarray(pos), np.int32)[lo:lo + G]
         if self.paged:
             full_pos = np.asarray(pos)
-            for slot in sorted(self._decodable):
-                if lo <= slot < lo + G:
-                    self._claim_guard(
-                        lambda s=slot: self.pool.extend(
-                            self._owner(s), int(full_pos[s]) + 1))
-                    self._set_table(slot)
-            self._apply_cow()
-            bt = self.block_tables[lo:lo + G].copy()
-            for i, slot in enumerate(range(lo, lo + G)):
-                if slot not in self._decodable:
-                    bt[i] = 0            # scratch page (kvpool.py)
+            with span("backend.pages") as sp:
+                claimed = self.pool.pages_claimed
+                for slot in sorted(self._decodable):
+                    if lo <= slot < lo + G:
+                        self._claim_guard(
+                            lambda s=slot: self.pool.extend(
+                                self._owner(s), int(full_pos[s]) + 1))
+                        self._set_table(slot)
+                self._apply_cow()
+                bt = self.block_tables[lo:lo + G].copy()
+                for i, slot in enumerate(range(lo, lo + G)):
+                    if slot not in self._decodable:
+                        bt[i] = 0            # scratch page (kvpool.py)
+                sp.counts["pages"] = self.pool.pages_claimed - claimed
             x = self.engine.feed_tokens(toks[:, None], paged=True)
             return x, jnp.asarray(pos_np), jnp.asarray(bt, jnp.int32)
         return self.engine.feed_tokens(toks), jnp.asarray(pos_np), None

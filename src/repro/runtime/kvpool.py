@@ -118,6 +118,7 @@ class KVPool:
         self._lengths: Dict[int, int] = {}       # owner -> tokens occupied
         self._cow_events: List[CowEvent] = []    # pending device-row copies
         self.cow_copies = 0                      # lifetime COW splits
+        self.pages_claimed = 0                   # lifetime pages claimed
 
     # ------------------------------------------------------------- helpers
     def _pages_for(self, num_tokens: int) -> int:
@@ -128,6 +129,7 @@ class KVPool:
             raise MemoryError(
                 f"pool exhausted: need {n} pages, {len(self._free)} free")
         pages = [self._free.pop() for _ in range(n)]
+        self.pages_claimed += n
         for pg in pages:
             assert pg not in self._refcount, f"page {pg} double-assigned"
             self._refcount[pg] = 1

@@ -30,63 +30,30 @@ surface and never imports ``runtime.backends`` (which imports us).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
-import time
 from typing import Dict, List, Optional, Set
 
 import numpy as np
 
-__all__ = [
-    "StageForward", "BoundarySend", "BoundaryRecv", "PrefillChunk",
-    "SampleToken", "Sync", "RoundResult", "DynamicPPQueue", "FusedQueue",
-    "make_queue",
-]
+from repro.runtime.tracing import span
+
+__all__ = ["RoundResult", "DynamicPPQueue", "FusedQueue", "make_queue"]
 
 
 # ---------------------------------------------------------------------------
-# instruction set — the executed program is logged, one record per issue,
-# so tests can pin instruction counts against commodel.pp_schedule_stats
+# instruction set — the dynamic queue counts what it executed, per
+# (kind, stage), so tests can pin it against commodel.pp_schedule_stats:
+#
+#   ("StageForward", s)     stage s's jitted fn ran for one group
+#   ("BoundarySend", s)     a group's boundary pair shipped off stage s
+#   ("BoundaryRecv", s)     ... and landed on stage s
+#   ("SampleToken", None)   the last stage's logits greedy-sampled
+#   ("PrefillChunk", None)  a prefill pass advanced (noted by the scheduler;
+#                           prefill itself stays on the fused backend path)
+#   ("Sync", None)          in-flight rounds drained before a cache/page
+#                           mutation (admission prefill, chunk, realloc)
 # ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class StageForward:
-    """Run stage ``stage``'s jitted fn for microbatch group ``mb``."""
-    mb: int
-    stage: int
-
-
-@dataclasses.dataclass(frozen=True)
-class BoundarySend:
-    """Ship the boundary pair off stage ``stage`` for group ``mb``."""
-    mb: int
-    stage: int
-
-
-@dataclasses.dataclass(frozen=True)
-class BoundaryRecv:
-    """Land the boundary pair on stage ``stage`` for group ``mb``."""
-    mb: int
-    stage: int
-
-
-@dataclasses.dataclass(frozen=True)
-class PrefillChunk:
-    """A prefill chunk advanced for slot ``mb`` (logged by the scheduler;
-    prefill itself stays on the fused per-backend path)."""
-    mb: int
-
-
-@dataclasses.dataclass(frozen=True)
-class SampleToken:
-    """Greedy-sample the last stage's logits for group ``mb``."""
-    mb: int
-
-
-@dataclasses.dataclass(frozen=True)
-class Sync:
-    """Barrier: all in-flight rounds were drained before a cache/page
-    mutation (admission prefill, chunk, realloc) could alias them."""
 
 
 @dataclasses.dataclass
@@ -118,6 +85,7 @@ class _Round:
     x: object
     pos: object
     bt: object
+    live: int
     stage: int = 0
     tr_count: int = 0
     tr_bytes: int = 0
@@ -158,7 +126,7 @@ class DynamicPPQueue:
         self.ticks = 0
         self.busy = [0] * self.p
         self.idle = [0] * self.p
-        self.log: List[object] = []
+        self.counts: collections.Counter = collections.Counter()
         self._mark = (0, [0] * self.p, [0] * self.p)
 
     # -- state ------------------------------------------------------------
@@ -178,11 +146,13 @@ class DynamicPPQueue:
 
     # -- refill -----------------------------------------------------------
 
-    def begin_round(self, g: int, tokens: np.ndarray, pos: np.ndarray):
-        """Start one decode round for group ``g`` from the scheduler's
-        per-slot token/pos state.  Page extends happen here, before any
-        instruction is issued, so pool exhaustion (MemoryError) surfaces
-        with the failed group *not* in flight."""
+    def begin_round(self, g: int, tokens: np.ndarray, pos: np.ndarray,
+                    live: int):
+        """Start one decode round for group ``g`` (``live`` of its rows
+        decoding) from the scheduler's per-slot token/pos state.  Page
+        extends happen here, before any instruction is issued, so pool
+        exhaustion (MemoryError) surfaces with the failed group *not* in
+        flight."""
         if any(r.mb == g for r in self._rounds):
             raise RuntimeError(f"group {g} already has a round in flight")
         x, pos_g, bt = self.backend.start_round(g, tokens, pos)
@@ -190,20 +160,22 @@ class DynamicPPQueue:
         lo = g * self.group_size
         self._rounds.append(_Round(
             mb=g, seq=self._seq, slots=list(range(lo, lo + self.group_size)),
-            x=x, pos=pos_g, bt=bt))
+            x=x, pos=pos_g, bt=bt, live=live))
 
     # -- drain ------------------------------------------------------------
 
     def pump(self) -> List[RoundResult]:
         """Tick until at least one round completes; return the completions
-        (empty only when nothing is in flight)."""
+        (empty only when nothing is in flight).  The pump's span is split
+        evenly over the rounds it completed, as their ``wall_s``."""
         if not self._rounds:
             return []
-        t0 = time.perf_counter()
         done: List[RoundResult] = []
-        while not done:
-            done = self._tick()
-        wall = (time.perf_counter() - t0) / len(done)
+        with span("sched.round",
+                  live=sum(r.live for r in self._rounds)) as sp:
+            while not done:
+                done = self._tick()
+        wall = sp.seconds / len(done)
         for res in done:
             res.wall_s = wall
         return done
@@ -216,7 +188,7 @@ class DynamicPPQueue:
         while self._rounds:
             out.extend(self.pump())
         if drained:
-            self.log.append(Sync())
+            self.counts["Sync", None] += 1
         return out
 
     def abort_all(self) -> None:
@@ -225,7 +197,7 @@ class DynamicPPQueue:
         self._rounds.clear()
 
     def note_prefill(self, slot: int) -> None:
-        self.log.append(PrefillChunk(mb=slot))
+        self.counts["PrefillChunk", None] += 1
 
     # -- internals --------------------------------------------------------
 
@@ -243,7 +215,7 @@ class DynamicPPQueue:
             out = self.backend.run_stage(cand.mb, s, cand.x, cand.pos,
                                          cand.bt)
             self.busy[s] += 1
-            self.log.append(StageForward(mb=cand.mb, stage=s))
+            self.counts["StageForward", s] += 1
             ran.append((cand, out))
         # tick tail: boundary moves + sample forcing happen after every
         # stage dispatch of the tick is in the air
@@ -256,8 +228,8 @@ class DynamicPPQueue:
                 for rec in eng.transfers[n0:]:
                     r.tr_count += rec.count
                     r.tr_bytes += rec.bytes
-                self.log.append(BoundarySend(mb=r.mb, stage=r.stage))
-                self.log.append(BoundaryRecv(mb=r.mb, stage=r.stage + 1))
+                self.counts["BoundarySend", r.stage] += 1
+                self.counts["BoundaryRecv", r.stage + 1] += 1
                 r.stage += 1
             else:
                 finished.append((r, out))
@@ -265,7 +237,7 @@ class DynamicPPQueue:
         for r, logits in finished:
             self._rounds.remove(r)
             toks = self.backend._first_token(logits)
-            self.log.append(SampleToken(mb=r.mb))
+            self.counts["SampleToken", None] += 1
             results.append(self._result(r, toks))
         return results
 
@@ -301,7 +273,6 @@ class FusedQueue:
         self.ticks = 0
         self.busy = [0]
         self.idle = [0]
-        self.log: List[object] = []
 
     @property
     def in_flight(self) -> int:
@@ -315,43 +286,37 @@ class FusedQueue:
     def pending_groups(self) -> Set[int]:
         return set() if self._round is None else {0}
 
-    def begin_round(self, g: int, tokens: np.ndarray, pos: np.ndarray):
+    def begin_round(self, g: int, tokens: np.ndarray, pos: np.ndarray,
+                    live: int):
         if self._round is not None:
             raise RuntimeError("fused queue already has a round pending")
-        self._round = (g, tokens, pos)
+        self._round = (g, tokens, pos, live)
 
     def pump(self) -> List[RoundResult]:
         if self._round is None:
             return []
-        g, tokens, pos = self._round
-        t0 = time.perf_counter()
+        g, tokens, pos, live = self._round
         # may raise (faults, pool exhaustion): the round is retained so the
         # recovery ladder retries it against the mutated token/pos state
-        nxt = self.backend.decode_step(tokens, pos)
-        wall = time.perf_counter() - t0
+        with span("sched.round", live=live) as sp:
+            nxt = self.backend.decode_step(tokens, pos)
         self._round = None
         self.ticks += 1
         self.busy[0] += 1
-        self.log.append(StageForward(mb=g, stage=0))
-        self.log.append(SampleToken(mb=g))
         tr = self.backend.drain_transfers()
         return [RoundResult(
             mb=g, slots=list(range(self.group_size)),
             tokens=np.asarray(nxt, np.int32), transfers=dict(tr),
-            wall_s=wall, ticks=1, stage_busy=[1], stage_idle=[0])]
+            wall_s=sp.seconds, ticks=1, stage_busy=[1], stage_idle=[0])]
 
     def sync(self) -> List[RoundResult]:
-        out: List[RoundResult] = []
-        if self._round is not None:
-            out = self.pump()
-            self.log.append(Sync())
-        return out
+        return self.pump()
 
     def abort_all(self) -> None:
         self._round = None
 
     def note_prefill(self, slot: int) -> None:
-        self.log.append(PrefillChunk(mb=slot))
+        pass
 
 
 def make_queue(backend):

@@ -62,6 +62,7 @@ from repro.runtime.backends import DecodeBackend
 from repro.runtime.faults import PermanentFault, TransientFault
 from repro.runtime.request import Request, RequestMetrics
 from repro.runtime.schedule import RoundResult, make_queue
+from repro.runtime.tracing import span
 
 
 # ---------------------------------------------------------------------------
@@ -130,7 +131,9 @@ class StepRecord:
     cached_prefix_len: Optional[int] = None   # prefix-cache hit (prefill,
     #                                           DESIGN.md §13): positions
     #                                           adopted instead of computed
-    wall_s: float = 0.0                   # host wall time of the round/pass
+    wall_s: float = 0.0                   # its span's duration (decode
+    #                                       round: sched.round; chunk:
+    #                                       sched.chunk; runtime/tracing.py)
     stage_busy: Optional[List[int]] = None   # per-stage busy ticks (decode)
     stage_idle: Optional[List[int]] = None   # per-stage idle ticks (decode)
 
@@ -723,10 +726,10 @@ class Scheduler:
         while True:
             try:
                 self._apply_fault("prefill")
-                t0 = time.perf_counter()
-                tok = self.backend.prefill_chunk(
-                    slot, st.prefix[start:end], start)
-                wall = time.perf_counter() - t0
+                with span("sched.chunk", rid=st.req.rid,
+                          tokens=end - start) as sp:
+                    tok = self.backend.prefill_chunk(
+                        slot, st.prefix[start:end], start)
                 break
             except TransientFault:
                 attempt += 1
@@ -751,7 +754,7 @@ class Scheduler:
             rid=st.req.rid,
             prefix_len=None if st.resume is None else len(st.prefix),
             cached_prefix_len=st.cached or None,
-            wall_s=wall))
+            wall_s=sp.seconds))
         self._step_i += 1
         if end < len(st.prefix):
             return
@@ -793,9 +796,9 @@ class Scheduler:
             if g in pending:
                 continue
             lo = g * G
-            if not any(s in self.active for s in range(lo, lo + G)):
-                continue
-            self._queue.begin_round(g, self.tokens, self.pos)
+            live = sum(1 for s in range(lo, lo + G) if s in self.active)
+            if live:
+                self._queue.begin_round(g, self.tokens, self.pos, live)
 
     def _pump_queue(self) -> Optional[List[RoundResult]]:
         """Refill + pump the instruction queue behind the recovery ladder
@@ -890,7 +893,14 @@ class Scheduler:
             self.backend.drain_transfers()
 
     def step(self) -> bool:
-        """One scheduler iteration; returns False when fully drained."""
+        """One scheduler iteration; returns False when fully drained.  Its
+        span carries the scheduler clock's reading at its start: the bridge
+        from the spans' clock to the one that stamps requests."""
+        with span("sched.step") as sp:
+            sp.counts["clock"] = self.clock.now()
+            return self._step()
+
+    def _step(self) -> bool:
         if not self.queue and not self.active and not self.prefilling \
                 and not self._queue.in_flight:
             return False
