@@ -56,8 +56,8 @@ from repro.kernels.quant_collective import (QUANT_DTYPES, chunk_amax,
                                             nibble_unpack, scales_from_amax)
 from repro.models.layers import apply_rope, decode_attn_mask, \
     decode_positions, gqa_attention, make_mask, mlp_apply, paged_attn_mask, \
-    paged_cache_update, paged_gather, ring_cache_update, ring_kv_assemble, \
-    rms_norm
+    paged_layer_gather, paged_layer_write, ring_cache_update, \
+    ring_kv_assemble, rms_norm
 from repro.models.transformer import greedy_decode_host_loop, \
     greedy_decode_loop
 
@@ -293,25 +293,51 @@ def _tp_layer_step(cfg, pl, x, pos, cache, axis, heads_t: int, kv_t: int,
     return out, {"k": ck, "v": cv}
 
 
-def _tp_layer_paged(cfg, pl, x, pos, cache, bt, axis, heads_t: int,
+def _tp_layer_paged(cfg, pl, x, pos, cache, layer, bt, axis, heads_t: int,
                     kv_t: int):
     """One transformer layer of a *paged* pass: x [B, S, h] is a prefill
     chunk (S > 1) or a decode token (S == 1) starting at per-sequence
-    positions ``pos`` [B]; K/V rows are scattered into the layer's
-    [P, ps, kv_t, D] page pool at the pages ``bt`` names and the logical
-    view is gathered back for attention (DESIGN.md §8).  The collective
-    schedule is exactly the contiguous layer's: 2 psums when TP-sharded —
-    paging is data movement, not communication."""
+    positions ``pos`` [B]; K/V rows are scattered into layer ``layer`` of
+    the whole [L, P, ps, kv_t, D] page pools at the pages ``bt`` names and
+    the logical view is gathered back for attention (DESIGN.md §8).  The
+    collective schedule is exactly the contiguous layer's: 2 psums when
+    TP-sharded — paging is data movement, not communication."""
     B, S = x.shape[:2]
     positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     xn = rms_norm(x, pl["ln1"], cfg.norm_eps)
     q, k, v = _tp_layer_qkv(cfg, pl, xn, positions, heads_t, kv_t)
-    ck, cv = paged_cache_update(cache["k"], cache["v"], k, v, pos, bt)
-    kg, vg = paged_gather(ck, bt), paged_gather(cv, bt)
+    ck = paged_layer_write(cache["k"], layer, k, pos, bt)
+    cv = paged_layer_write(cache["v"], layer, v, pos, bt)
+    kg = paged_layer_gather(ck, layer, bt)
+    vg = paged_layer_gather(cv, layer, bt)
     mask = paged_attn_mask(kg.shape[1], pos, S)
     attn = gqa_attention(q, kg, vg, mask).reshape(B, S,
                                                   heads_t * cfg.head_dim)
     return _tp_layer_out(cfg, pl, x, attn, axis), {"k": ck, "v": cv}
+
+
+def _tp_layers_paged(cfg, blocks, x, pos, cache, bt, axis, heads_t: int,
+                     kv_t: int, unroll: bool):
+    """Every layer of ``blocks`` for one paged pass.  The whole page pools
+    are threaded from layer to layer and indexed by layer, never sliced
+    per layer or re-stacked, so a donated pool is updated in place: the
+    unrolled loop passes them on, the scan carries them beside the layer
+    index and scans only the parameters."""
+    if unroll:
+        for l in range(jax.tree.leaves(blocks)[0].shape[0]):
+            x, cache = _tp_layer_paged(cfg, _layer_slice(blocks, l), x, pos,
+                                       cache, l, bt, axis, heads_t, kv_t)
+        return x, cache
+
+    def body(carry, pl):
+        h, c, l = carry
+        h, c = _tp_layer_paged(cfg, pl, h, pos, c, l, bt, axis, heads_t,
+                               kv_t)
+        return (h, c, l + 1), None
+
+    (x, cache, _), _ = jax.lax.scan(
+        body, (x, cache, jnp.zeros((), jnp.int32)), blocks)
+    return x, cache
 
 
 def _layer_slice(blocks, l):
@@ -670,22 +696,8 @@ def tp_paged_step(cfg: ModelConfig, mesh: Mesh, unroll: bool = False,
 
     def fn(params, cache, tokens, pos, bt):
         x = _embed_tokens(cfg, params, tokens, axis)
-        if unroll:
-            new_cache = []
-            for l in range(cfg.num_layers):
-                x, c = _tp_layer_paged(cfg, _layer_slice(params["blocks"], l),
-                                       x, pos, _layer_slice(cache, l), bt,
-                                       axis, heads_t, kv_t)
-                new_cache.append(c)
-            cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_cache)
-        else:
-            def body(h, inp):
-                pl, cl = inp
-                h, c = _tp_layer_paged(cfg, pl, h, pos, cl, bt, axis,
-                                       heads_t, kv_t)
-                return h, c
-
-            x, cache = jax.lax.scan(body, x, (params["blocks"], cache))
+        x, cache = _tp_layers_paged(cfg, params["blocks"], x, pos, cache, bt,
+                                    axis, heads_t, kv_t, unroll)
         logits = _head(cfg, params, x[:, -1, :], axis)
         return logits, cache
 
@@ -996,7 +1008,6 @@ class PipelineEngine:
         decode stage — ``commodel.hybrid_stage_collectives`` — because the
         page scatter/gather is shard-local."""
         cfg, t, p = self.cfg, self.t, self.p
-        lo, hi = stage_layer_range(cfg, p, s)
         heads_t, kv_t = cfg.num_heads // t, cfg.num_kv_heads // t
         axis = self._tp_axis
         first, last = s == 0, s == p - 1
@@ -1004,23 +1015,8 @@ class PipelineEngine:
         def fn(params, cache, x_or_tokens, pos, bt):
             x = (_embed_tokens(cfg, params, x_or_tokens, axis) if first
                  else self._boundary_in(x_or_tokens))
-            if self.unroll:
-                new_cache = []
-                for i in range(hi - lo):
-                    x, c = _tp_layer_paged(
-                        cfg, _layer_slice(params["blocks"], i), x, pos,
-                        _layer_slice(cache, i), bt, axis, heads_t, kv_t)
-                    new_cache.append(c)
-                cache = jax.tree.map(lambda *xs: jnp.stack(xs), *new_cache)
-            else:
-                def body(h, inp):
-                    pl, cl = inp
-                    h, c = _tp_layer_paged(cfg, pl, h, pos, cl, bt, axis,
-                                           heads_t, kv_t)
-                    return h, c
-
-                x, cache = jax.lax.scan(
-                    body, x, (params["blocks"], cache))
+            x, cache = _tp_layers_paged(cfg, params["blocks"], x, pos, cache,
+                                        bt, axis, heads_t, kv_t, self.unroll)
             out = (self._head_out(params, x[:, -1, :]) if last
                    else self._boundary_out(x))
             return out, cache

@@ -4,12 +4,14 @@ Block API (used by the scan trunk in ``transformer.py``):
 
     init_blocks(rng, cfg, L, dtype)              -> stacked param pytree [L, ...]
     block_apply(cfg, p_l, x, positions, mask, cache=None, pos=None,
-                build_cache_w=None, block_table=None) -> (y, cache_out, aux)
+                build_cache_w=None, block_table=None, layer=None)
+        -> (y, cache_out, aux)
 
 ``cache`` is the per-layer cache slice in decode mode; ``build_cache_w`` asks a
 full-sequence pass to emit a (ring-buffer) cache of width W for the engine;
 ``block_table`` switches the dense block to the paged-cache path
-(DESIGN.md §8), where ``cache`` is a [P, ps, Hkv, D] page pool.
+(DESIGN.md §8), where ``cache`` is the whole [L, P, ps, Hkv, D] page pool and
+``layer`` the index of this block's layer in it.
 """
 from __future__ import annotations
 
@@ -40,7 +42,8 @@ def build_ring_cache(k, v, w: int):
 
 def attention_apply(cfg: ModelConfig, p, xn, positions, mask,
                     cache=None, pos=None, build_cache_w=None, n_heads=None,
-                    block_table=None, cp_axis=None, cp_size: int = 1):
+                    block_table=None, layer=None, cp_axis=None,
+                    cp_size: int = 1):
     """Self-attention over a normalized input xn [B,S,h].
 
     Returns (attn_out [B,S,n_heads*D], cache_out).
@@ -72,16 +75,18 @@ def attention_apply(cfg: ModelConfig, p, xn, positions, mask,
 
     if cache is not None and block_table is not None:
         # paged path (DESIGN.md §8): the chunk's K/V rows are scattered into
-        # the [P, ps, Hkv, D] page pool at the pages the block table names,
-        # then the logical view is gathered back for attention.  Serves both
-        # chunked prefill (S > 1) and paged decode (S == 1); ``pos`` is the
-        # [B] vector of start positions.
+        # layer ``layer`` of the whole [L, P, ps, Hkv, D] page pool at the
+        # pages the block table names, then the logical view is gathered
+        # back for attention.  Serves both chunked prefill (S > 1) and paged
+        # decode (S == 1); ``pos`` is the [B] vector of start positions.
         with jax.named_scope("page_write"):
-            ck, cv = layers.paged_cache_update(cache["k"], cache["v"], k, v,
-                                               pos, block_table)
+            ck = layers.paged_layer_write(cache["k"], layer, k, pos,
+                                          block_table)
+            cv = layers.paged_layer_write(cache["v"], layer, v, pos,
+                                          block_table)
         with jax.named_scope("page_gather"):
-            kg = layers.paged_gather(ck, block_table)
-            vg = layers.paged_gather(cv, block_table)
+            kg = layers.paged_layer_gather(ck, layer, block_table)
+            vg = layers.paged_layer_gather(cv, layer, block_table)
         pmask = layers.paged_attn_mask(kg.shape[1], pos, S)
         out = gqa_attention(q, kg, vg, pmask)
         cache_out = {"k": ck, "v": cv}
@@ -118,12 +123,14 @@ def init_dense_blocks(rng, cfg: ModelConfig, L: int, dtype):
 
 def dense_block_apply(cfg: ModelConfig, p, x, positions, mask,
                       cache=None, pos=None, build_cache_w=None,
-                      block_table=None, cp_axis=None, cp_size: int = 1):
+                      block_table=None, layer=None, cp_axis=None,
+                      cp_size: int = 1):
     with jax.named_scope("attention"):
         attn_out, cache_out = attention_apply(
             cfg, p, rms_norm(x, p["ln1"], cfg.norm_eps), positions, mask,
             cache=cache, pos=pos, build_cache_w=build_cache_w,
-            block_table=block_table, cp_axis=cp_axis, cp_size=cp_size)
+            block_table=block_table, layer=layer, cp_axis=cp_axis,
+            cp_size=cp_size)
         x = x + attn_out @ p["wo"]
     with jax.named_scope("mlp"):
         x = x + mlp_apply(p, rms_norm(x, p["ln2"], cfg.norm_eps),

@@ -148,40 +148,43 @@ def decode_attn_mask(cache_len: int, pos, window=None):
     return m[:, None, None, None, :]
 
 
-def paged_cache_update(cache_k, cache_v, k, v, pos, block_table):
-    """Write a chunk's K/V rows into their block-table pages.
+def paged_slots(pos, q_len: int, block_table, page_size: int):
+    """Physical (page, row-in-page) of a chunk's logical positions.
 
-    cache_k/v: [P, ps, Hkv, D] page pools (one layer); k/v: [B, S, Hkv, D];
     pos: [B] start positions; block_table: [B, n] int32 — logical page j of
     sequence b lives at physical page ``block_table[b, j]``.  Logical
-    position q maps to physical row ``block_table[b, q // ps] * ps + q % ps``
-    of the flattened pool.  Every live page is owned by exactly one sequence
-    (runtime/kvpool.py), so the scatter destinations are distinct — except
-    for the reserved scratch page 0, which inactive slots alias on purpose
-    (their garbage writes must land somewhere harmless).
+    position q maps to row ``q % page_size`` of page
+    ``block_table[b, q // page_size]``.  Returns (page, off), both [B, S].
+    Every live page is owned by exactly one sequence (runtime/kvpool.py),
+    so the destinations are distinct — except for the reserved scratch
+    page 0, which inactive slots alias on purpose (their garbage writes
+    must land somewhere harmless).
     """
-    P, ps, Hkv, D = cache_k.shape
-    B, S = k.shape[:2]
-    lp = pos[:, None] + jnp.arange(S)[None, :]             # [B, S] logical
-    phys = jnp.take_along_axis(block_table, lp // ps, axis=1)
-    rows = (phys * ps + lp % ps).reshape(-1)               # [B*S] physical
-    ck = cache_k.reshape(P * ps, Hkv, D).at[rows].set(
-        k.reshape(B * S, Hkv, D)).reshape(P, ps, Hkv, D)
-    cv = cache_v.reshape(P * ps, Hkv, D).at[rows].set(
-        v.reshape(B * S, Hkv, D)).reshape(P, ps, Hkv, D)
-    return ck, cv
+    lp = pos[:, None] + jnp.arange(q_len)[None, :]         # [B, S] logical
+    page = jnp.take_along_axis(block_table, lp // page_size, axis=1)
+    return page, lp % page_size
 
 
-def paged_gather(pages, block_table):
-    """Materialize the logical KV view named by a block table:
-    pages [P, ps, Hkv, D] + table [B, n] -> [B, n*ps, Hkv, D].  Row j*ps+r
-    of the result is logical position j*ps+r of sequence b; entries past the
-    sequence's length alias whatever page the table names there (scratch
-    page 0 for unallocated blocks) and must be masked by the caller."""
+def paged_layer_write(pool, layer, x, pos, block_table):
+    """Write a chunk's K or V rows x [B, S, Hkv, D] into layer ``layer`` of
+    the whole [L, P, ps, Hkv, D] page pool, at the pages the block table
+    names (``paged_slots``).  One scatter on the full pool: with the pool
+    donated and carried through the layer loop, XLA updates it in place
+    (DESIGN.md §8).  ``layer`` may be a traced scalar."""
+    page, off = paged_slots(pos, x.shape[1], block_table, pool.shape[2])
+    return pool.at[layer, page, off].set(x)
+
+
+def paged_layer_gather(pool, layer, block_table):
+    """The logical KV view of layer ``layer`` named by a block table:
+    pool [L, P, ps, Hkv, D] + table [B, n] -> [B, n*ps, Hkv, D].  Row
+    j*ps+r of the result is logical position j*ps+r of sequence b; entries
+    past the sequence's length alias whatever page the table names there
+    (scratch page 0 for unallocated blocks) and must be masked by the
+    caller."""
     B, n = block_table.shape
-    P, ps, Hkv, D = pages.shape
-    out = pages[block_table]                               # [B, n, ps, Hkv, D]
-    return out.reshape(B, n * ps, Hkv, D)
+    _, _, ps, Hkv, D = pool.shape
+    return pool[layer, block_table].reshape(B, n * ps, Hkv, D)
 
 
 def paged_attn_mask(kv_len: int, pos, q_len: int):

@@ -188,19 +188,24 @@ class Model:
         return x, aux, cache
 
     def _scan_paged(self, params, x, positions, cache, pos, block_table):
+        """The paged layer loop.  The whole [L, P, ps, Hkv, D] pool rides
+        in the carry beside the layer index, and only the parameters are
+        scanned: each layer scatters its rows into the pool and gathers
+        its view from it by index, so a donated pool is updated in place
+        — never sliced per layer, re-stacked or copied (DESIGN.md §8)."""
         cfg = self.cfg
 
-        def body(carry, inp):
-            p_l, c_l = inp
-            h, aux = carry
-            y, c, a = self.block_apply(cfg, p_l, h, positions, None,
-                                       cache=c_l, pos=pos,
-                                       block_table=block_table)
-            return (y, aux + a), c
+        def body(carry, p_l):
+            h, aux, pool, l = carry
+            y, pool, a = self.block_apply(cfg, p_l, h, positions, None,
+                                          cache=pool, pos=pos,
+                                          block_table=block_table, layer=l)
+            return (y, aux + a, pool, l + 1), None
 
-        (x, aux), new_cache = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), (params["blocks"], cache))
-        return x, aux, new_cache
+        (x, aux, cache, _), _ = jax.lax.scan(
+            body, (x, jnp.zeros((), jnp.float32), cache,
+                   jnp.zeros((), jnp.int32)), params["blocks"])
+        return x, aux, cache
 
     def _scan_decode(self, params, x, positions, cache, pos):
         cfg = self.cfg

@@ -73,7 +73,7 @@ from repro.config.base import ModelConfig
 from repro.core import parallel_exec as px
 from repro.core.commodel import DEFAULT_QUANT_CHUNK, CommOp, \
     chunked_prefill_ops, comm_ops_for
-from repro.models.layers import paged_cache_update
+from repro.models.layers import paged_slots
 from repro.models.transformer import get_model
 from repro.runtime.kvpool import KVPool
 from repro.runtime.prefix_index import PrefixIndex
@@ -149,14 +149,10 @@ def _seed_pages(pools, small, bt):
     names — the CP gather-into-pages handoff (DESIGN.md §9).  Pure data
     movement on unsharded axes (kv heads keep their TP sharding), jitted
     with the pools donated so the write happens in place."""
-    pos = jnp.zeros((1,), jnp.int32)
-
-    def per_layer(pk, pv, k, v):
-        return paged_cache_update(pk, pv, k, v, pos, bt)
-
-    ck, cv = jax.vmap(per_layer)(pools["k"], pools["v"],
-                                 small["k"], small["v"])
-    return {"k": ck, "v": cv}
+    page, off = paged_slots(jnp.zeros((1,), jnp.int32), small["k"].shape[2],
+                            bt, pools["k"].shape[2])
+    return {name: pools[name].at[:, page, off].set(small[name])
+            for name in ("k", "v")}
 
 
 class _BackendBase:

@@ -9,8 +9,9 @@ position ``q`` maps to physical row ``table[q // page_size] * page_size +
 q % page_size``.
 
 This module is the host-side bookkeeping only — pure Python over integers,
-no jax.  The device side (``models/layers.paged_gather`` and the engines'
-paged steps) consumes the block tables as [B, pages_per_seq] int32 arrays.
+no jax.  The device side (``models/layers.paged_layer_write`` /
+``paged_layer_gather`` and the engines' paged steps) consumes the block
+tables as [B, pages_per_seq] int32 arrays.
 
 Invariants (property-tested in tests/test_kvpool.py):
 

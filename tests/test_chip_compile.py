@@ -11,6 +11,8 @@ The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -26,6 +28,8 @@ from repro.models.transformer import get_model
 
 HBM_BYTES = 16e9          # one v5e chip
 NUM_PAGES, PAGE_SIZE, SLOTS, MAX_LEN = 1025, 16, 8, 2048
+# the benchmark cell's pool: 24 slots of 2048 positions in pages of 16
+CELL_PAGES, CELL_SLOTS = 3073, 24
 
 
 @pytest.fixture(scope="module")
@@ -78,6 +82,16 @@ def _step_args(batch, q_len, sharding):
             int_arg((batch, MAX_LEN // PAGE_SIZE)))
 
 
+def _pool_shaped_moves(hlo: str, pages: int, kv_heads: int, head_dim: int):
+    """Lines of a compiled module whose ``copy``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` yields a whole pool or a whole layer of one:
+    a result shape ending in [pages, page_size, kv_heads, head_dim]."""
+    shape = rf"\[(\d+,)*{pages},{PAGE_SIZE},{kv_heads},{head_dim}\]"
+    op = r"\b(copy|dynamic-slice|dynamic-update-slice)\("
+    return [line for line in hlo.splitlines()
+            if re.search(rf"= \w+{shape}", line) and re.search(op, line)]
+
+
 @pytest.mark.parametrize("batch,q_len", [(SLOTS, 1), (1, 256)],
                          ids=["decode", "prefill_chunk"])
 def test_gspmd_paged_step_fits_one_chip(cfg, one_chip, batch, q_len):
@@ -117,6 +131,43 @@ def test_tp4_paged_step_compiles_on_four_chips(cfg, topo,
     # replicated norms
     assert 0 < mem.argument_size_in_bytes < HBM_BYTES / 4
     assert "all-reduce" in compiled.as_text()
+
+
+@pytest.mark.parametrize("engine", ["gspmd", "tp4"])
+@pytest.mark.parametrize("batch,q_len", [(CELL_SLOTS, 1), (1, 256)],
+                         ids=["decode", "prefill_chunk"])
+def test_paged_step_updates_pool_in_place(cfg, topo, no_persistent_cache,
+                                          engine, batch, q_len):
+    """At the benchmark cell's pool, the paged step with its pool donated
+    updates the pool in place: no temporary near the pool's size, and no
+    copy, slice or stacking of a whole pool (or a whole layer of it) in
+    the compiled module.  The tp4 step is checked per device."""
+    model = get_model(cfg)
+    if engine == "gspmd":
+        one = SingleDeviceSharding(topo.devices[0])
+        params = _shapes(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                         one)
+        pool_sharding, arg_sharding, kv_heads = one, one, cfg.num_kv_heads
+        step = jax.jit(model.paged_step, donate_argnums=(1,))
+    else:
+        mesh = Mesh(np.asarray(topo.devices).reshape(4), ("tp",),
+                    axis_types=(AxisType.Auto,))
+        shard = lambda spec: NamedSharding(mesh, spec)
+        params = _shapes(jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+                         jax.tree.map(shard, px.tp_param_specs(cfg),
+                                      is_leaf=lambda x: isinstance(x, P)))
+        pool_sharding = shard(P(None, None, None, "tp", None))
+        arg_sharding, kv_heads = shard(P()), cfg.num_kv_heads // 4
+        step = px.tp_paged_step(cfg, mesh)
+    cache = _shapes(jax.eval_shape(
+        lambda: model.init_paged_cache(CELL_PAGES, PAGE_SIZE)), pool_sharding)
+    compiled = step.lower(params, cache,
+                          *_step_args(batch, q_len, arg_sharding)).compile()
+    pool_bytes = 2 * (cfg.num_layers * CELL_PAGES * PAGE_SIZE * kv_heads
+                      * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.05 * pool_bytes
+    assert _pool_shaped_moves(compiled.as_text(), CELL_PAGES, kv_heads,
+                              cfg.head_dim) == []
 
 
 @pytest.mark.parametrize("kernel", ["chunk_amax", "chunk_quantize",

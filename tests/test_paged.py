@@ -12,6 +12,8 @@
 4. Scheduler fix: iterations with no decoding slot never invoke the jitted
    decode step.
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -71,27 +73,159 @@ def _solo_reference(cfg, params, req):
 # ---------------------------------------------------------------------------
 
 
+def _per_layer_write(cache_k, cache_v, k, v, pos, block_table):
+    """Reference: the per-layer page write the paged loop used before it
+    carried the whole pool — one layer's [P, ps, Hkv, D] pools flattened to
+    rows, row ``block_table[b, q // ps] * ps + q % ps`` for position q."""
+    P, ps, Hkv, D = cache_k.shape
+    B, S = k.shape[:2]
+    lp = pos[:, None] + jnp.arange(S)[None, :]
+    phys = jnp.take_along_axis(block_table, lp // ps, axis=1)
+    rows = (phys * ps + lp % ps).reshape(-1)
+    ck = cache_k.reshape(P * ps, Hkv, D).at[rows].set(
+        k.reshape(B * S, Hkv, D)).reshape(P, ps, Hkv, D)
+    cv = cache_v.reshape(P * ps, Hkv, D).at[rows].set(
+        v.reshape(B * S, Hkv, D)).reshape(P, ps, Hkv, D)
+    return ck, cv
+
+
+def _per_layer_gather(pages, block_table):
+    """Reference: one layer's logical view, pages [P, ps, Hkv, D] ->
+    [B, n*ps, Hkv, D]."""
+    B, n = block_table.shape
+    _, ps, Hkv, D = pages.shape
+    return pages[block_table].reshape(B, n * ps, Hkv, D)
+
+
+def _block_tables(pool, lanes, n, lengths):
+    """Block tables [lanes, n] for sequences of ``lengths`` tokens; a lane
+    of length 0 is inactive and keeps every entry on scratch page 0."""
+    bt = np.zeros((lanes, n), np.int32)
+    for b, length in enumerate(lengths):
+        if length:
+            row = pool.allocate(b, length)
+            bt[b, :len(row)] = row
+    return jnp.asarray(bt)
+
+
+def _live_pages(bt):
+    return np.unique(np.asarray(bt)[np.asarray(bt) > 0])
+
+
 def test_paged_update_gather_matches_contiguous():
-    """Writing a chunk through the block table then gathering the logical
-    view reproduces the contiguous [B, S, H, D] layout exactly."""
+    """Writing a chunk through the block table into one layer of the pool,
+    then gathering that layer's logical view, reproduces the contiguous
+    [B, S, H, D] layout exactly and leaves the other layer untouched."""
     rng = np.random.default_rng(0)
-    B, S, H, D, ps = 2, 11, 2, 4, 4
+    B, S, H, D, ps, L = 2, 11, 2, 4, 4, 2
     n = -(-S // ps) + 1
     k = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
-    pool = KVPool(num_pages=2 * n + 1, page_size=ps)
-    bt = np.zeros((B, n), np.int32)
-    for b in range(B):
-        row = pool.allocate(b, S)
-        bt[b, :len(row)] = row
-    pages = jnp.zeros((2 * n + 1, ps, H, D), jnp.float32)
-    ck, cv = layers.paged_cache_update(pages, pages, k, v,
-                                       jnp.zeros((B,), jnp.int32),
-                                       jnp.asarray(bt))
-    got_k = layers.paged_gather(ck, jnp.asarray(bt))[:, :S]
-    got_v = layers.paged_gather(cv, jnp.asarray(bt))[:, :S]
+    bt = _block_tables(KVPool(num_pages=2 * n + 1, page_size=ps), B,
+                        n, [S, S])
+    pool = jnp.zeros((L, 2 * n + 1, ps, H, D), jnp.float32)
+    pos = jnp.zeros((B,), jnp.int32)
+    ck = layers.paged_layer_write(pool, 1, k, pos, bt)
+    cv = layers.paged_layer_write(pool, jnp.int32(1), v, pos, bt)
+    got_k = layers.paged_layer_gather(ck, 1, bt)[:, :S]
+    got_v = layers.paged_layer_gather(cv, jnp.int32(1), bt)[:, :S]
     np.testing.assert_array_equal(np.asarray(got_k), np.asarray(k))
     np.testing.assert_array_equal(np.asarray(got_v), np.asarray(v))
+    assert not np.asarray(ck[0]).any() and not np.asarray(cv[0]).any()
+
+
+@pytest.mark.parametrize("q_len,pos", [(5, [3, 0, 9]), (1, [13, 0, 6])],
+                         ids=["chunk", "decode"])
+def test_layer_indexed_pair_matches_per_layer_pair(q_len, pos):
+    """The layer-indexed write and gather on the whole [L, P, ps, Hkv, D]
+    pool agree bitwise with the per-layer pair on that layer's slice, on
+    every live page, with lane 1 inactive (aliasing scratch page 0)."""
+    rng = np.random.default_rng(1)
+    L, P, ps, H, D, n = 3, 12, 4, 2, 4, 5
+    lanes = len(pos)
+    bt = _block_tables(KVPool(num_pages=P, page_size=ps), lanes, n,
+                        [p + q_len if b != 1 else 0
+                         for b, p in enumerate(pos)])
+    pos = jnp.asarray(pos, jnp.int32)
+    pool = jnp.asarray(rng.standard_normal((L, P, ps, H, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((lanes, q_len, H, D)), jnp.float32)
+    live = _live_pages(bt)
+    for layer in range(L):
+        got = layers.paged_layer_write(pool, jnp.int32(layer), k, pos, bt)
+        ref, _ = _per_layer_write(pool[layer], pool[layer], k, k, pos, bt)
+        np.testing.assert_array_equal(np.asarray(got[layer][live]),
+                                      np.asarray(ref[live]))
+        others = np.asarray([i for i in range(L) if i != layer])
+        np.testing.assert_array_equal(np.asarray(got[others]),
+                                      np.asarray(pool[others]))
+        active = np.asarray([0, 2])
+        np.testing.assert_array_equal(
+            np.asarray(layers.paged_layer_gather(got, layer, bt))[active],
+            np.asarray(_per_layer_gather(ref, bt))[active])
+
+
+def _per_layer_paged_step(cfg, params, cache, tokens, pos, bt):
+    """Reference paged pass in the per-layer form: each layer's pool slice
+    is cut out, written and gathered by the per-layer pair, and the slices
+    are stacked back into a new pool."""
+    model = get_model(cfg)
+    x = model._embed(params, tokens)
+    B, S = tokens.shape
+    D = cfg.head_dim
+    positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    new_k, new_v = [], []
+    for l in range(cfg.num_layers):
+        p = {name: w[l] for name, w in params["blocks"].items()}
+        xn = layers.rms_norm(x, p["ln1"], cfg.norm_eps)
+        q = layers.apply_rope((xn @ p["wq"]).reshape(B, S, cfg.num_heads, D),
+                              positions, cfg.rope_theta)
+        k = layers.apply_rope(
+            (xn @ p["wk"]).reshape(B, S, cfg.num_kv_heads, D), positions,
+            cfg.rope_theta)
+        v = (xn @ p["wv"]).reshape(B, S, cfg.num_kv_heads, D)
+        ck, cv = _per_layer_write(cache["k"][l], cache["v"][l], k, v, pos,
+                                  bt)
+        kg, vg = _per_layer_gather(ck, bt), _per_layer_gather(cv, bt)
+        mask = layers.paged_attn_mask(kg.shape[1], pos, S)
+        attn = layers.gqa_attention(q, kg, vg, mask)
+        x = x + attn.reshape(B, S, cfg.num_heads * D) @ p["wo"]
+        x = x + layers.mlp_apply(p, layers.rms_norm(x, p["ln2"],
+                                                    cfg.norm_eps),
+                                 cfg.activation)
+        new_k.append(ck)
+        new_v.append(cv)
+    logits = model._head(params, x[:, -1:, :])[:, 0]
+    return logits, {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
+
+
+def test_paged_step_matches_per_layer_reference(setup):
+    """``Model.paged_step``, which carries the whole pool through its layer
+    loop, gives the per-layer form's logits and pool bitwise: a prefill
+    chunk, then a decode round with lane 1 inactive."""
+    cfg, params = setup
+    model = get_model(cfg)
+    rng = np.random.default_rng(2)
+    P, n, lanes = 16, MAX_LEN // PAGE, 3
+    kv = KVPool(num_pages=P, page_size=PAGE)
+    bt = _block_tables(kv, lanes, n, [13, 0, 6])
+    cache = model.init_paged_cache(P, PAGE)
+    step = jax.jit(model.paged_step)
+    ref_step = jax.jit(functools.partial(_per_layer_paged_step, cfg))
+    live = _live_pages(bt)
+    got_c = ref_c = cache
+    for tokens, pos, table in [
+            (rng.integers(2, cfg.vocab_size, (1, 12)), [0], bt[:1]),
+            (rng.integers(2, cfg.vocab_size, (lanes, 1)), [12, 0, 5], bt)]:
+        tokens = jnp.asarray(tokens, jnp.int32)
+        pos = jnp.asarray(pos, jnp.int32)
+        got_l, got_c = step(params, got_c, tokens, pos, table)
+        ref_l, ref_c = ref_step(params, ref_c, tokens, pos, table)
+    active = np.asarray([0, 2])
+    np.testing.assert_array_equal(np.asarray(got_l)[active],
+                                  np.asarray(ref_l)[active])
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got_c[name])[:, live],
+                                      np.asarray(ref_c[name])[:, live])
 
 
 def test_paged_attn_mask_is_causal_per_sequence():
