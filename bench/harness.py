@@ -5,8 +5,10 @@ Everything a cell needs is found by name under ``bench/``:
 - ``BENCHMARK.json`` (the checkout's root) lists the cell's configuration,
   traffic mix and chips, and the metrics;
 - ``configs/<config>.json``: the model's published sizes, the program's
-  model id, its dtype and the reference it is checked against
-  (``reference/<reference>.py``);
+  model id, its dtype, the reference it is checked against
+  (``reference/<reference>.py``) and its architecture (``arch/<arch>.py``:
+  the check of the program's config, the weight layout, and the FLOP and
+  byte counts the metric readers take);
 - ``deploy/<cell>.json``: the keyword arguments of the program's
   ``make_backend`` (``make_backend``) and ``Scheduler`` (``scheduler``),
   passed on as they are; a key that either does not take is an error;
@@ -198,21 +200,16 @@ class CompileLog:
 
 # ---------------------------------------------------------------- building
 def program_config(cfg: dict):
-    """The program's config for this file, checked against its numbers."""
+    """The program's config for this file, checked by the file's
+    architecture module (``bench/arch/<arch>.py``)."""
     from repro.configs import get_config
     pc = get_config(cfg["model_id"])
-    want = {"d_model": cfg["hidden_size"], "d_ff": cfg["intermediate_size"],
-            "num_layers": cfg["num_hidden_layers"],
-            "num_heads": cfg["num_attention_heads"],
-            "num_kv_heads": cfg["num_key_value_heads"],
-            "head_dim": cfg["head_dim"], "vocab_size": cfg["vocab_size"],
-            "rope_theta": cfg["rope_theta"], "norm_eps": cfg["rms_norm_eps"],
-            "tie_embeddings": cfg["tie_word_embeddings"],
-            "dtype": cfg["dtype"]}
-    got = {k: getattr(pc, k) for k in want}
-    if got != want or pc.family != "dense" or pc.activation != "swiglu":
+    try:
+        modules.arch(cfg).check_program(cfg, pc)
+    except ValueError as e:
         raise RunFailed(f"the program's {cfg['model_id']} is not the "
-                        f"configuration file's: {got} != {want}")
+                        f"configuration file's, by bench/arch/"
+                        f"{cfg['arch']}.py: {e}") from e
     return pc
 
 

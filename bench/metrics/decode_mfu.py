@@ -1,7 +1,8 @@
 """Share of the chips' bf16 peak that the window's decode rounds reach:
-model FLOPs of the live sequences' tokens (``bench/flops.py``, from
-shapes) over the rounds' host call time times chips times peak, %."""
-from bench import flops
+model FLOPs of the live sequences' tokens (the cell's architecture module,
+``bench/arch/<arch>.py``, from shapes) over the rounds' host call time
+times chips times peak, %."""
+from bench import modules
 
 
 def read(run):
@@ -10,6 +11,8 @@ def read(run):
     secs = sum(s for s, _ in calls)
     if not secs or run.peak is None:
         return None
-    work = sum(flops.decode_flops(run.spec.cfg, pos) for _, pos in calls)
+    cfg = run.spec.cfg
+    arch = modules.arch(cfg)
+    work = sum(arch.decode_flops(cfg, pos) for _, pos in calls)
     return 100.0 * work / (secs * run.spec.chips
                            * run.peak["bf16_flops_per_s"])

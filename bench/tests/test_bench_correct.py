@@ -4,16 +4,18 @@ fault the one-chip cell can have planted underneath the timed path: a
 decode round that leaves the KV cache unchanged, half of the batch left
 out, a token altered where it is produced.  The float8 control, judged by
 the same checks at the committed limit, is not correct."""
+import dataclasses
 import time
 
 import numpy as np
 import pytest
 
-from bench import harness
+from bench import harness, modules
 
 # Eight layers: deep enough that the float8 control's rounding adds up
 # past the cell's limit, as it does at the cell's own 24 layers.
-CFG = {"name": "tiny", "reference": "dense_gqa", "dtype": "bfloat16",
+CFG = {"name": "tiny", "model_id": "internlm2-1.8b", "arch": "dense_gqa",
+       "reference": "dense_gqa", "dtype": "bfloat16",
        "hidden_size": 256, "intermediate_size": 512, "num_hidden_layers": 8,
        "num_attention_heads": 8, "num_key_value_heads": 4, "head_dim": 32,
        "vocab_size": 1024, "rms_norm_eps": 1e-5, "rope_theta": 10000.0,
@@ -40,25 +42,24 @@ def _spec():
         per_layer=real.per_layer)
 
 
-def _config(cfg):
-    from repro.config.base import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        dtype=cfg["dtype"])
+def _tiny_program(monkeypatch):
+    """The program's registry gives ``CFG``'s model at ``CFG``'s sizes: its
+    own config with the fields the architecture module takes from the file
+    replaced, so that the harness's check by that module runs as it is."""
+    from repro import configs
+    real = configs.get_config
+    fields = modules.arch(CFG).program_fields(CFG)
+    monkeypatch.setattr(configs, "get_config", lambda model_id: (
+        dataclasses.replace(real(model_id), **fields)))
 
 
 def _run(spec, monkeypatch, wrap=None, trace=False):
     """The harness's whole run, past its look for a chip (CPU devices, no
-    peaks) and with the tiny config in place of the program's registry."""
+    peaks) and with the tiny config in the program's registry."""
     import jax
     monkeypatch.setattr(harness, "chips",
                         lambda n: (jax.devices()[:n], None))
-    monkeypatch.setattr(harness, "program_config", _config)
+    _tiny_program(monkeypatch)
     if wrap is not None:
         build = harness.build_backend
         monkeypatch.setattr(harness, "build_backend",

@@ -8,7 +8,7 @@ import re
 
 import pytest
 
-from bench import flops, harness, modules, weights
+from bench import harness, modules
 
 ROOT = harness.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -115,6 +115,7 @@ def test_config_files(bench):
             cfg = json.load(f)
         assert cfg["source"] == c["source"]
         assert cfg["reduced"] == c["reduced"]
+        modules.arch(cfg)                  # with every function of its API
         harness.program_config(cfg)        # the program runs these sizes
 
 
@@ -146,11 +147,12 @@ def test_weights_match_the_program_layout(bench):
     for c in bench["configs"]:
         with open(os.path.join(ROOT, c["file"])) as f:
             cfg = json.load(f)
+        arch = modules.arch(cfg)
         want = jax.eval_shape(get_model(harness.program_config(cfg)).init,
                               jax.random.PRNGKey(0))
         flat = dict(jax.tree_util.tree_flatten_with_path(want)[0])
         shapes = {tuple(k.key for k in path): leaf.shape
                   for path, leaf in flat.items()}
-        assert shapes == {p: s for p, (s, _) in weights.leaf_specs(cfg).items()}
-        assert flops.param_count(cfg) == sum(
+        assert shapes == {p: s[0] for p, s in arch.leaf_specs(cfg).items()}
+        assert arch.param_count(cfg) == sum(
             int(leaf.size) for leaf in jax.tree.leaves(want))
